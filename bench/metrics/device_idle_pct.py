@@ -1,0 +1,7 @@
+"""Share of the traced window in which no op ran on the device (chip mean)."""
+
+
+def read(s):
+    if s.window_s <= 0 or s.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - s.busy_s / s.window_s)
