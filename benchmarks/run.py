@@ -255,6 +255,25 @@ def bench_fft_engines(n: int = 16):
 # host's devices allow)
 # ---------------------------------------------------------------------------
 
+def _solver_model_us(solver) -> float:
+    """The perf model's time for one step of ``solver``'s plan (µs):
+    diagonal-kernel solvers price the spectral roundtrip of their plan
+    (fused when the plan streams it), the others the same roundtrip
+    composed. A nominal-substrate estimate; the drift gate in
+    ``compare.py`` tracks its error against a baseline."""
+    from repro.core import perfmodel as pm
+    from repro.solvers.base import SpectralSolver
+
+    g = solver.plan.grid
+    diagonal = (type(solver).spectral_kernel
+                is not SpectralSolver.spectral_kernel)
+    est = pm.estimate_roundtrip_seconds(
+        solver.n, g.pu, g.pv, spec=solver.plan.spec(),
+        fused=solver.plan.fused_roundtrip and diagonal,
+        mu=max(solver.components, 1), pu_axes=g.u_sizes, pv_axes=g.v_sizes)
+    return est * 1e6
+
+
 def bench_solvers(n: int = 16):
     import jax
 
@@ -275,7 +294,7 @@ def bench_solvers(n: int = 16):
         _row(f"solver_{case}/N{n}/mesh{pu}x{pv}/us_per_step", st["mean_us"],
              "", config={"case": case, "n": n, "mesh": f"{pu}x{pv}",
                          **solver.plan_config()},
-             stats=st, model_us=solver.predict_step_us())
+             stats=st, model_us=_solver_model_us(solver))
         if SOLVERS[case].spectral_kernel is SpectralSolver.spectral_kernel:
             continue  # no diagonal spectral kernel — nothing to fuse
         fused = make_solver(case, mesh, (n, n, n), dtype="float32",
@@ -286,7 +305,7 @@ def bench_solvers(n: int = 16):
              st["mean_us"], "",
              config={"case": case, "n": n, "mesh": f"{pu}x{pv}",
                      **fused.plan_config()},
-             stats=st, model_us=fused.predict_step_us())
+             stats=st, model_us=_solver_model_us(fused))
 
 
 # ---------------------------------------------------------------------------

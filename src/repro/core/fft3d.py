@@ -63,10 +63,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import compat, obs
-from repro.core import comm, perfmodel as pm, precision
+from repro.core import comm, precision
 from repro.core.decomposition import CommDAG, PencilGrid, fft3d_dag
 from repro.core.engine_spec import EngineSpec
 from repro.kernels import ops as kops
+from repro.obs import stages
 
 Schedule = Literal["sequential", "pipelined"]
 VectorMode = Literal["parallel", "streaming"]
@@ -139,45 +140,42 @@ class FFT3DPlan:
         return self.n[0] // 2 + 1 if self.real else self.n[0]
 
 
+def _pass(plan, stage, xr, xi, inverse=False):
+    """One 1D-FFT pass along the last axis, in its stage scope."""
+    with jax.named_scope(stage):
+        return kops.fft1d(xr, xi, axis=-1, backend=plan.backend,
+                          inverse=inverse)
+
+
 def _fftx(plan, xr, xi):
-    if plan.real:
-        yr, yi = kops.rfft1d(xr, axis=-1, backend=plan.backend, packed=plan.r2c_packed)
-        pad = plan.kx - plan.kx_keep
-        if pad:
-            pw = [(0, 0)] * (yr.ndim - 1) + [(0, pad)]
+    if not plan.real:
+        return _pass(plan, stages.FFT_X, xr, xi)
+    with jax.named_scope(stages.FFT_X):
+        yr, yi = kops.rfft1d(xr, axis=-1, backend=plan.backend,
+                             packed=plan.r2c_packed)
+    pad = plan.kx - plan.kx_keep
+    if pad:
+        pw = [(0, 0)] * (yr.ndim - 1) + [(0, pad)]
+        with jax.named_scope(stages.RELAYOUT_R2C_PAD):
             yr, yi = jnp.pad(yr, pw), jnp.pad(yi, pw)
-        return yr, yi
-    return kops.fft1d(xr, xi, axis=-1, backend=plan.backend)
+    return yr, yi
 
 
 def _ifftx(plan, xr, xi):
-    if plan.real:
+    if not plan.real:
+        return _pass(plan, stages.FFT_X, xr, xi, inverse=True)
+    with jax.named_scope(stages.RELAYOUT_R2C_CROP):
         xr = xr[..., : plan.kx_keep]
         xi = xi[..., : plan.kx_keep]
-        return kops.irfft1d(xr, xi, n=plan.n[0], axis=-1, backend=plan.backend)
-    return kops.fft1d(xr, xi, axis=-1, backend=plan.backend, inverse=True)
+    with jax.named_scope(stages.FFT_X):
+        return kops.irfft1d(xr, xi, n=plan.n[0], axis=-1,
+                            backend=plan.backend)
+
 
 
 # ---------------------------------------------------------------------------
 # local (inside-shard_map) forward / inverse
 # ---------------------------------------------------------------------------
-
-def _phase_span(plan: "FFT3DPlan", name: str, dim: str):
-    """A ``trace/...`` span around one fold phase, annotated with the perf
-    model's wire prediction for that phase. These run *inside* jit tracing
-    of the shard_map body, so they fire once per compilation and time
-    tracing, not execution — they exist to pin the DAG structure and the
-    per-phase model numbers onto the trace (see README "Observability")."""
-    if not obs.is_enabled():
-        return obs.NULL_SPAN
-    g = plan.grid
-    sizes = g.u_sizes if dim == "u" else g.v_sizes
-    wire_us = pm.estimate_fold_seconds(
-        plan.n, g.pu, g.pv, sizes, comm_engine=plan.comm_engine) * 1e6
-    return obs.span(name, engine=plan.comm_engine, grid_dim=dim,
-                    dim_sizes=list(int(q) for q in sizes),
-                    model_wire_us=round(wire_us, 3))
-
 
 def fft3d_local(plan: FFT3DPlan, xr, xi=None):
     """Forward 3D FFT of the local pencil (any leading axes).
@@ -196,18 +194,16 @@ def fft3d_local(plan: FFT3DPlan, xr, xi=None):
     def butterflies_x(cr, ci):
         return _fftx(plan, cr, ci)
 
-    with _phase_span(plan, "trace/fft3d.fold_xy", "u"):
-        yr, yi = eng.run_fold(dag.step("xy"), butterflies_x, (xr, xi))
+    yr, yi = eng.run_fold(dag.step("xy"), butterflies_x, (xr, xi))
 
     # Phase Y + Y↔Z fold over grid dim v (tasks E–H), slabbable along kx
     def butterflies_y(cr, ci):
-        return kops.fft1d(cr, ci, axis=-1, backend=plan.backend)
+        return _pass(plan, stages.FFT_Y, cr, ci)
 
-    with _phase_span(plan, "trace/fft3d.fold_yz", "v"):
-        yr, yi = eng.run_fold(dag.step("yz"), butterflies_y, (yr, yi))
+    yr, yi = eng.run_fold(dag.step("yz"), butterflies_y, (yr, yi))
 
     # Phase Z (tasks I–K)
-    return kops.fft1d(yr, yi, axis=-1, backend=plan.backend)
+    return _pass(plan, stages.FFT_Z, yr, yi)
 
 
 def ifft3d_local(plan: FFT3DPlan, kr, ki):
@@ -218,21 +214,19 @@ def ifft3d_local(plan: FFT3DPlan, kr, ki):
     eng = plan.engine()
     dag = plan.dag()
     obs.metrics.inc("fft3d.retraces.inverse")
-    yr, yi = kops.fft1d(kr, ki, axis=-1, backend=plan.backend, inverse=True)
+    yr, yi = _pass(plan, stages.FFT_Z, kr, ki, inverse=True)
 
     def butterflies_y_inv(ur, ui):
-        return kops.fft1d(ur, ui, axis=-1, backend=plan.backend, inverse=True)
+        return _pass(plan, stages.FFT_Y, ur, ui, inverse=True)
 
-    with _phase_span(plan, "trace/fft3d.unfold_yz", "v"):
-        yr, yi = eng.run_unfold(dag.step("yz"), butterflies_y_inv, (yr, yi))
+    yr, yi = eng.run_unfold(dag.step("yz"), butterflies_y_inv, (yr, yi))
 
     def butterflies_x_inv(ur, ui):
         if plan.real:
             return (_ifftx(plan, ur, ui),)
         return _ifftx(plan, ur, ui)
 
-    with _phase_span(plan, "trace/fft3d.unfold_xy", "u"):
-        out = eng.run_unfold(dag.step("xy"), butterflies_x_inv, (yr, yi))
+    out = eng.run_unfold(dag.step("xy"), butterflies_x_inv, (yr, yi))
     if plan.real:
         return out[0] if isinstance(out, tuple) and len(out) == 1 else out
     return out
@@ -267,14 +261,15 @@ class DiagonalKernel:
         """Multiply the planar spectrum by the kernel; ``[lo, hi)`` selects
         the kx rows of a slab (slab axis −3 of the Z-pencil)."""
         dr, di = self.dr, self.di
-        if lo is not None:
-            axis = dr.ndim - 3
-            dr = jax.lax.slice_in_dim(dr, lo, hi, axis=axis)
-            if di is not None:
-                di = jax.lax.slice_in_dim(di, lo, hi, axis=axis)
-        if di is None:
-            return kr * dr, ki * dr
-        return kr * dr - ki * di, kr * di + ki * dr
+        with jax.named_scope(stages.POINTWISE_DIAGONAL):
+            if lo is not None:
+                axis = dr.ndim - 3
+                dr = jax.lax.slice_in_dim(dr, lo, hi, axis=axis)
+                if di is not None:
+                    di = jax.lax.slice_in_dim(di, lo, hi, axis=axis)
+            if di is None:
+                return kr * dr, ki * dr
+            return kr * dr - ki * di, kr * di + ki * dr
 
     def arrays(self):
         """The raw planar multiplier pair (``di`` may be None) for engines
@@ -311,36 +306,31 @@ def spectral_roundtrip_local(plan: FFT3DPlan, kernel: DiagonalKernel,
     def butterflies_x(cr, ci):
         return _fftx(plan, cr, ci)
 
-    with _phase_span(plan, "trace/fft3d.fold_xy", "u"):
-        yr, yi = eng.run_fold(dag.step("xy"), butterflies_x, (xr, xi))
+    yr, yi = eng.run_fold(dag.step("xy"), butterflies_x, (xr, xi))
 
     def butterflies_y(cr, ci):
-        return kops.fft1d(cr, ci, axis=-1, backend=plan.backend)
+        return _pass(plan, stages.FFT_Y, cr, ci)
 
     def butterflies_y_inv(ur, ui):
-        return kops.fft1d(ur, ui, axis=-1, backend=plan.backend,
-                          inverse=True)
+        return _pass(plan, stages.FFT_Y, ur, ui, inverse=True)
 
     def middle(zr, zi, lo, hi):
         # everything at the Z pencil, for kx rows [lo, hi): the remaining
         # transform, the spectral multiply, and its inverse
-        zr, zi = kops.fft1d(zr, zi, axis=-1, backend=plan.backend)
+        zr, zi = _pass(plan, stages.FFT_Z, zr, zi)
         zr, zi = kernel.apply(zr, zi, lo, hi)
-        return kops.fft1d(zr, zi, axis=-1, backend=plan.backend,
-                          inverse=True)
+        return _pass(plan, stages.FFT_Z, zr, zi, inverse=True)
 
-    with _phase_span(plan, "trace/fft3d.roundtrip_yz", "v"):
-        yr, yi = eng.run_roundtrip(dag.step("yz"), butterflies_y, middle,
-                                   butterflies_y_inv, (yr, yi),
-                                   diag=kernel.arrays())
+    yr, yi = eng.run_roundtrip(dag.step("yz"), butterflies_y, middle,
+                               butterflies_y_inv, (yr, yi),
+                               diag=kernel.arrays())
 
     def butterflies_x_inv(ur, ui):
         if plan.real:
             return (_ifftx(plan, ur, ui),)
         return _ifftx(plan, ur, ui)
 
-    with _phase_span(plan, "trace/fft3d.unfold_xy", "u"):
-        out = eng.run_unfold(dag.step("xy"), butterflies_x_inv, (yr, yi))
+    out = eng.run_unfold(dag.step("xy"), butterflies_x_inv, (yr, yi))
     if plan.real:
         return out[0] if isinstance(out, tuple) and len(out) == 1 else out
     return out
@@ -453,9 +443,6 @@ def make_fft3d(mesh, n, *, spec: EngineSpec | None = None,
     attrs = {
         "engine": plan.comm_engine, "n": list(n),
         "mesh": "x".join(str(int(q)) for q in grid.u_sizes + grid.v_sizes),
-        "model_predicted_us": round(pm.estimate_plan_seconds(
-            n, grid.pu, grid.pv, spec=s, mu=max(components, 1),
-            pu_axes=grid.u_sizes, pv_axes=grid.v_sizes) * 1e6, 3),
     }
     fwd = obs.traced_call(fwd, "dispatch/fft3d.fwd", attrs)
     inv = obs.traced_call(inv, "dispatch/fft3d.inv", attrs)

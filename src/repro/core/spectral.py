@@ -16,12 +16,14 @@ FFT → spectral → iFFT → local cycle) is built from them.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from repro import compat
 from repro.core import precision
 from repro.core.fft3d import FFT3DPlan, fft3d_vector_local, ifft3d_vector_local
+from repro.obs import stages
 
 
 _flat_index = compat.flat_axis_index
@@ -130,15 +132,16 @@ def gradient(plan: FFT3DPlan, fr, fi):
 
 def curl(plan: FFT3DPlan, vr, vi):
     """Vorticity ω̂ = i k × v̂ for a planar (3, ...) spectral field."""
-    kx, ky, kz = local_wavenumbers(plan, vr.dtype)
+    with jax.named_scope(stages.POINTWISE_CURL):
+        kx, ky, kz = local_wavenumbers(plan, vr.dtype)
 
-    def cross_k(ar):
-        return jnp.stack([ky * ar[2] - kz * ar[1],
-                          kz * ar[0] - kx * ar[2],
-                          kx * ar[1] - ky * ar[0]])
+        def cross_k(ar):
+            return jnp.stack([ky * ar[2] - kz * ar[1],
+                              kz * ar[0] - kx * ar[2],
+                              kx * ar[1] - ky * ar[0]])
 
-    # i*(k × v): (i k) × (vr + i vi) = -(k × vi) + i (k × vr)
-    return -cross_k(vi), cross_k(vr)
+        # i*(k × v): (i k) × (vr + i vi) = -(k × vi) + i (k × vr)
+        return -cross_k(vi), cross_k(vr)
 
 
 def project_divergence_free(plan: FFT3DPlan, vr, vi):
@@ -146,15 +149,16 @@ def project_divergence_free(plan: FFT3DPlan, vr, vi):
 
     Used by the Navier–Stokes solver to enforce incompressibility.
     """
-    kx, ky, kz = local_wavenumbers(plan, vr.dtype)
-    ks = (kx, ky, kz)
-    k2 = k_squared(plan, vr.dtype)
-    dot_r = sum(ks[c] * vr[c] for c in range(3))
-    dot_i = sum(ks[c] * vi[c] for c in range(3))
-    inv = jnp.where(k2 > 0, 1.0 / jnp.maximum(k2, 1e-30), 0.0)
-    pr = jnp.stack([vr[c] - ks[c] * dot_r * inv for c in range(3)])
-    pi = jnp.stack([vi[c] - ks[c] * dot_i * inv for c in range(3)])
-    return pr, pi
+    with jax.named_scope(stages.POINTWISE_PROJECT):
+        kx, ky, kz = local_wavenumbers(plan, vr.dtype)
+        ks = (kx, ky, kz)
+        k2 = k_squared(plan, vr.dtype)
+        dot_r = sum(ks[c] * vr[c] for c in range(3))
+        dot_i = sum(ks[c] * vi[c] for c in range(3))
+        inv = jnp.where(k2 > 0, 1.0 / jnp.maximum(k2, 1e-30), 0.0)
+        pr = jnp.stack([vr[c] - ks[c] * dot_r * inv for c in range(3)])
+        pi = jnp.stack([vi[c] - ks[c] * dot_i * inv for c in range(3)])
+        return pr, pi
 
 
 def rotational_nonlinear_term(plan: FFT3DPlan, vr, vi, *,
@@ -171,12 +175,14 @@ def rotational_nonlinear_term(plan: FFT3DPlan, vr, vi, *,
     u = ifft3d_vector_local(plan, vr, vi, vector_mode=vector_mode)
     wr, wi = curl(plan, vr, vi)
     w = ifft3d_vector_local(plan, wr, wi, vector_mode=vector_mode)
-    uxw = jnp.stack([u[1] * w[2] - u[2] * w[1],
-                     u[2] * w[0] - u[0] * w[2],
-                     u[0] * w[1] - u[1] * w[0]])
+    with jax.named_scope(stages.POINTWISE_CROSS):
+        uxw = jnp.stack([u[1] * w[2] - u[2] * w[1],
+                         u[2] * w[0] - u[0] * w[2],
+                         u[0] * w[1] - u[1] * w[0]])
     nr, ni = fft3d_vector_local(plan, uxw, None, vector_mode=vector_mode)
-    mask = dealias_mask(plan, nr.dtype)
-    nr, ni = nr * mask, ni * mask
+    with jax.named_scope(stages.POINTWISE_DEALIAS):
+        mask = dealias_mask(plan, nr.dtype)
+        nr, ni = nr * mask, ni * mask
     if project:
         nr, ni = project_divergence_free(plan, nr, ni)
     return nr, ni

@@ -26,10 +26,12 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from repro import compat, obs
+from repro.obs import stages
 
 MODES = ("switched", "torus")
 
@@ -107,8 +109,9 @@ def all_to_all_blocks(x, axes: tuple[str, ...], *, split_axis: int,
         name = axes if len(axes) > 1 else axes[0]
         _meter_exchange(axes, _axis_size(axes), 1, (x,),
                         dispatch_kind="all_to_all", dispatches=1)
-        return lax.all_to_all(x, name, split_axis=split_axis,
-                              concat_axis=concat_axis, tiled=True)
+        with jax.named_scope(stages.FOLD_EXCHANGE):
+            return lax.all_to_all(x, name, split_axis=split_axis,
+                                  concat_axis=concat_axis, tiled=True)
     return _ring_all_to_all(x, axes, split_axis=split_axis,
                             concat_axis=concat_axis)
 
@@ -120,18 +123,20 @@ def stack_blocks(x, p: int, split_axis: int):
     ``kernels.ring_rdma``) so their wire layouts are identical."""
     n = x.shape[split_axis]
     assert n % p == 0, (n, p)
-    xs = x.reshape(x.shape[:split_axis] + (p, n // p)
-                   + x.shape[split_axis + 1:])
-    return jnp.moveaxis(xs, split_axis, 0)
+    with jax.named_scope(stages.RELAYOUT_FOLD_PACK):
+        xs = x.reshape(x.shape[:split_axis] + (p, n // p)
+                       + x.shape[split_axis + 1:])
+        return jnp.moveaxis(xs, split_axis, 0)
 
 
 def merge_blocks(o, p: int, concat_axis: int):
     """Inverse of the receive side: fold the leading rank axis of ``o`` into
     ``concat_axis`` in rank-major block order (tiled all_to_all semantics)."""
-    o = jnp.moveaxis(o, 0, concat_axis)
-    return o.reshape(o.shape[:concat_axis]
-                     + (p * o.shape[concat_axis + 1],)
-                     + o.shape[concat_axis + 2:])
+    with jax.named_scope(stages.RELAYOUT_FOLD_UNPACK):
+        o = jnp.moveaxis(o, 0, concat_axis)
+        return o.reshape(o.shape[:concat_axis]
+                         + (p * o.shape[concat_axis + 1],)
+                         + o.shape[concat_axis + 2:])
 
 
 def staged_exchange(arrs, axes, *, split_axis: int, concat_axis: int,
@@ -202,12 +207,18 @@ def ring_exchange(arrs, axes, *, split_axis: int, concat_axis: int,
                                concat_axis=concat_axis, exchange=ring_exchange,
                                interleave=interleave)
     p = _axis_size(axes)
-    me = _flat_axis_index(axes)
-    name = axes if len(axes) > 1 else axes[0]
     _meter_exchange(axes, p, ring_rounds(p), arrs,
                     dispatch_kind="ppermute",
                     dispatches=ring_rounds(p) * len(arrs))
+    with jax.named_scope(stages.FOLD_EXCHANGE):
+        return _ring_rounds(arrs, axes, p, split_axis=split_axis,
+                            concat_axis=concat_axis, interleave=interleave)
 
+
+def _ring_rounds(arrs, axes, p, *, split_axis, concat_axis, interleave):
+    """The P−1 rounds of :func:`ring_exchange` over one mesh axis."""
+    me = _flat_axis_index(axes)
+    name = axes if len(axes) > 1 else axes[0]
     xss = [stack_blocks(x, p, split_axis) for x in arrs]
     # own block stays local
     outs = [lax.dynamic_update_index_in_dim(
@@ -252,15 +263,22 @@ def ring_exchange_bidi(arrs, axes, *, split_axis: int, concat_axis: int,
                                exchange=ring_exchange_bidi,
                                interleave=interleave)
     p = _axis_size(axes)
-    me = _flat_axis_index(axes)
-    name = axes if len(axes) > 1 else axes[0]
     # ppermute dispatches: one clockwise stream per round, plus the
     # counter-clockwise stream except the shared-farthest-block round
     ccw = bidi_rounds(p) - (1 if p % 2 == 0 else 0)
     _meter_exchange(axes, p, bidi_rounds(p), arrs,
                     dispatch_kind="ppermute",
                     dispatches=(bidi_rounds(p) + ccw) * len(arrs))
+    with jax.named_scope(stages.FOLD_EXCHANGE):
+        return _bidi_rounds(arrs, axes, p, split_axis=split_axis,
+                            concat_axis=concat_axis, interleave=interleave)
 
+
+def _bidi_rounds(arrs, axes, p, *, split_axis, concat_axis, interleave):
+    """The ``bidi_rounds(P)`` rounds of :func:`ring_exchange_bidi` over one
+    mesh axis."""
+    me = _flat_axis_index(axes)
+    name = axes if len(axes) > 1 else axes[0]
     xss = [stack_blocks(x, p, split_axis) for x in arrs]
     # own block stays local
     outs = [lax.dynamic_update_index_in_dim(
@@ -317,17 +335,20 @@ def permute_last3(a, perm: tuple[int, int, int]):
     (`_swap_last2`).
     """
     d = a.ndim
-    return a.transpose(tuple(range(d - 3)) + tuple(d - 3 + i for i in perm))
+    with jax.named_scope(stages.RELAYOUT_PERMUTE):
+        return a.transpose(tuple(range(d - 3)) + tuple(d - 3 + i for i in perm))
 
 
 def _swap_last3(a):
     perm = tuple(range(a.ndim - 3)) + (a.ndim - 1, a.ndim - 2, a.ndim - 3)
-    return a.transpose(perm)
+    with jax.named_scope(stages.RELAYOUT_PERMUTE):
+        return a.transpose(perm)
 
 
 def _swap_last2(a):
     perm = tuple(range(a.ndim - 3)) + (a.ndim - 3, a.ndim - 1, a.ndim - 2)
-    return a.transpose(perm)
+    with jax.named_scope(stages.RELAYOUT_PERMUTE):
+        return a.transpose(perm)
 
 
 def xy_fold(a, u_axes, *, mode="switched"):

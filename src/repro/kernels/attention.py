@@ -107,5 +107,6 @@ def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 256,
             pltpu.VMEM((blk_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh, vh)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -135,6 +135,7 @@ def fft1d_mxu(x_re, x_im, *, tb: int | None = None, interpret: bool | None = Non
         out_shape=[jax.ShapeDtypeStruct((bp, n1, n2), dtype),
                    jax.ShapeDtypeStruct((bp, n1, n2), dtype)],
         interpret=interpret,
+        name="fft_mxu",
     )(xr, xi, jnp.asarray(d2r), jnp.asarray(d2i), jnp.asarray(twr),
       jnp.asarray(twi), jnp.asarray(d1r), jnp.asarray(d1i))
     # step 4: X[k1 + n1·k2] = D[k1, k2]  →  transpose

@@ -154,6 +154,7 @@ def fft1d_pallas(x_re, x_im, *, tb: int | None = None, interpret: bool | None = 
         ],
         out_shape=out_shape,
         interpret=interpret,
+        name="fft_radix2",
     )(xr, xi, twr, twi)
     yr = yr[:b].reshape(*lead, n)
     yi = yi[:b].reshape(*lead, n)

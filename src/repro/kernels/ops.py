@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.fft_radix2 import fft1d_pallas, ifft1d_pallas
+from repro.obs import stages
 
 BACKENDS = ("pallas", "ref", "jnp", "mxu")
 
@@ -81,7 +82,8 @@ def rfft1d(x, *, axis: int = -1, backend: str = "pallas", packed: bool = False):
         yr, yi = _ref.rfft_packed_planar(xr) if backend != "pallas" else _rfft_packed_pallas(xr)
     else:
         zr, zi = fft1d(xr, jnp.zeros_like(xr), axis=-1, backend=backend)
-        yr, yi = zr[..., : n // 2 + 1], zi[..., : n // 2 + 1]
+        with jax.named_scope(stages.RELAYOUT_R2C_CROP):
+            yr, yi = zr[..., : n // 2 + 1], zi[..., : n // 2 + 1]
     return _unmove_last(yr, axis), _unmove_last(yi, axis)
 
 
@@ -113,8 +115,9 @@ def irfft1d(x_re, x_im, *, n: int, axis: int = -1, backend: str = "pallas"):
     k = xr.shape[-1]
     assert k == n // 2 + 1, (k, n)
     # rebuild bins n/2+1 .. n-1 by conjugate symmetry
-    idx = jnp.arange(n // 2 - 1, 0, -1)
-    fr = jnp.concatenate([xr, xr[..., idx]], axis=-1)
-    fi = jnp.concatenate([xi, -xi[..., idx]], axis=-1)
+    with jax.named_scope(stages.RELAYOUT_C2R_HERMITIAN):
+        idx = jnp.arange(n // 2 - 1, 0, -1)
+        fr = jnp.concatenate([xr, xr[..., idx]], axis=-1)
+        fi = jnp.concatenate([xi, -xi[..., idx]], axis=-1)
     yr, _ = fft1d(fr, fi, axis=-1, backend=backend, inverse=True)
     return _unmove_last(yr, axis)

@@ -61,6 +61,7 @@ from repro import compat
 from repro.core import transpose as tr
 from repro.kernels.fft_radix2 import butterfly_stages
 from repro.kernels.ref import is_pow2, twiddle_lanes_np
+from repro.obs import stages
 
 
 def use_rdma() -> bool:
@@ -97,6 +98,7 @@ def nic_take(xs, idx):
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((1,) + xs.shape[1:], xs.dtype),
         interpret=True,
+        name="nic_take",
     )(_smem_index(idx), xs)
 
 
@@ -112,6 +114,7 @@ def nic_place(out, blk, idx):
         out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
         input_output_aliases={2: 0},
         interpret=True,
+        name="nic_place",
     )(_smem_index(idx), blk, out)
 
 
@@ -127,16 +130,18 @@ def _ring_interpret(arrs, axes, *, split_axis: int, concat_axis: int,
     name = axes if len(axes) > 1 else axes[0]
 
     xss = [tr.stack_blocks(x, p, split_axis) for x in arrs]
-    outs = [nic_place(jnp.zeros_like(xs), nic_take(xs, me), me) for xs in xss]
-    follow = None
-    for r in range(1, p):
-        perm = [(i, (i + r) % p) for i in range(p)]
-        recvs = [lax.ppermute(nic_take(xs, (me + r) % p), name, perm)
-                 for xs in xss]
-        if follow is None and interleave is not None:
-            follow = interleave()
-        outs = [nic_place(o, recv, (me - r) % p)
-                for o, recv in zip(outs, recvs)]
+    with jax.named_scope(stages.FOLD_EXCHANGE):
+        outs = [nic_place(jnp.zeros_like(xs), nic_take(xs, me), me)
+                for xs in xss]
+        follow = None
+        for r in range(1, p):
+            perm = [(i, (i + r) % p) for i in range(p)]
+            recvs = [lax.ppermute(nic_take(xs, (me + r) % p), name, perm)
+                     for xs in xss]
+            if follow is None and interleave is not None:
+                follow = interleave()
+            outs = [nic_place(o, recv, (me - r) % p)
+                    for o, recv in zip(outs, recvs)]
     return [tr.merge_blocks(o, p, concat_axis) for o in outs], follow
 
 
@@ -405,19 +410,21 @@ def _ring_rdma_tpu(arrs, axes, *, split_axis: int, concat_axis: int,
     # per-direction semaphore slots for the bidi kernel (dim 1: cw, ccw)
     sem_shape = ((max(tr.bidi_rounds(p), 1), 2, len(xss)) if bidi
                  else (max(p - 1, 1), len(xss)))
-    results = pl.pallas_call(
-        kernel,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA(sem_shape),
-            pltpu.SemaphoreType.DMA(sem_shape),
-        ],
-        compiler_params=pltpu.CompilerParams(collective_id=0),
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope(stages.FOLD_EXCHANGE):
+        results = pl.pallas_call(
+            kernel,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.SemaphoreType.DMA,
+                pltpu.SemaphoreType.DMA(sem_shape),
+                pltpu.SemaphoreType.DMA(sem_shape),
+            ],
+            compiler_params=pltpu.CompilerParams(collective_id=0),
+            interpret=interpret,
+            name="rdma_bidi_ring" if bidi else "rdma_ring",
+        )(*operands)
 
     outs = [tr.merge_blocks(o.reshape(xs.shape), p, concat_axis)
             for o, xs in zip(results[:len(xss)], stacked)]

@@ -5,10 +5,11 @@ The observability layer of the stack: a thread-safe span :class:`Tracer`
 export for Perfetto), a counters/gauges :class:`Metrics` registry (wire
 bytes, ppermute/all-to-all dispatches, per-axis exchange rounds, plan-cache
 hits/misses, retraces), and the :func:`traced_call` dispatch-boundary
-wrapper. Instrumentation lives in ``core.transpose``/``core.comm`` (wire
-metrics at trace time), ``core.fft3d`` (phase spans with perf-model
-predictions), ``solvers.base`` (step/observable spans) and ``repro.tuning``
-(sweep spans, cache counters).
+wrapper, and the stage vocabulary (:mod:`repro.obs.stages`) the program
+puts on its compiled work with ``jax.named_scope``. Instrumentation lives
+in ``core.transpose``/``core.comm`` (wire metrics at trace time),
+``core.fft3d`` (retrace counters), ``solvers.base`` (step/observable
+spans) and ``repro.tuning`` (sweep spans, cache counters).
 
 Disabled — the default — every entry point returns before allocating:
 ``span()`` hands back a shared no-op singleton, ``metrics.inc`` is one
@@ -16,15 +17,17 @@ branch, ``traced_call`` wrappers tail-call straight through. Enable with
 :func:`enable` (the CLIs' ``--trace PATH`` flags do), export with
 :func:`write_chrome_trace` / :func:`summary_table`.
 
-Import of this package is jax-free; jax is only touched inside an enabled
-``traced_call`` (to block on dispatched results).
+Import of this package is jax-free; jax is only imported by an enabled
+span, which also enters a ``jax.profiler.TraceAnnotation`` of its name so
+that it lands on a profiler trace's host plane beside the device ops.
 
 What jit lets us see: **spans cannot live inside jitted shard_map
 bodies** — a ``with`` block there times Python *tracing*, which runs once
 per compilation. The span layer therefore wraps dispatch boundaries
-(``dispatch/...`` spans, blocking on results), while inside-jit structure
-is captured as trace-time metrics and ``trace/...`` spans annotated with
-the perf model's per-phase predictions. See README "Observability".
+(``dispatch/...`` spans, which time the dispatch and do not block), while
+the work inside the compiled step is named by the stage scopes and timed
+from a device trace (``bench/stagetime.py``); structure that only exists
+at trace time is counted by the metrics registry.
 """
 
 from __future__ import annotations
@@ -75,9 +78,9 @@ def span(name: str, /, **attrs):
 
 
 def traced_call(fn, name: str, attrs: dict | None = None) -> TracedCallable:
-    """Wrap ``fn`` so every call is a ``dispatch/...`` span that blocks on
-    the result (accurate wall time under async dispatch). Attributes are
-    fixed at wrap time; jit surfaces (``.lower`` etc.) forward through."""
+    """Wrap ``fn`` so every call is a ``dispatch/...`` span around its
+    dispatch (it does not wait for the result). Attributes are fixed at
+    wrap time; jit surfaces (``.lower`` etc.) forward through."""
     return TracedCallable(fn, name, tracer, attrs)
 
 

@@ -6,17 +6,15 @@ manager. Spans nest per thread (each records its parent's name and depth),
 and the recorded events serialize straight into the Chrome trace event
 format (``obs.export.chrome_trace``) that Perfetto / chrome://tracing load.
 
-Two span flavors by naming convention (see README "Observability"):
-
-* ``dispatch/...`` — wall time at a jit dispatch boundary. Accurate only if
-  the span blocks on the dispatched work before closing;
-  :func:`traced_call` does exactly that.
-* ``trace/...`` — Python *tracing* time inside a jitted function body.
-  These fire once per compilation, not per execution: they show the comm
-  DAG's structure and the perf model's per-phase predictions, not runtime.
+Each live span also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so under a ``jax.profiler`` trace it sits on the profiler's host
+plane, on the same clock as the device ops. A ``dispatch/...`` span times
+the host side of a jit dispatch and does not wait for the device: the
+device time of that call is read from the device trace, where the
+program's stage scopes (``repro.obs.stages``) name every op.
 
 Disabled (the default), ``tracer.span(name)`` returns a module-level no-op
-singleton — no event, no allocation, no lock.
+singleton — no event, no allocation, no lock, no jax import.
 """
 
 from __future__ import annotations
@@ -55,7 +53,8 @@ NULL_SPAN = _NullSpan()
 class Span:
     """One live span (context manager). Created only when tracing is on."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0_us", "tid", "parent", "depth")
+    __slots__ = ("tracer", "name", "attrs", "t0_us", "tid", "parent", "depth",
+                 "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
@@ -65,6 +64,7 @@ class Span:
         self.tid = 0
         self.parent = ""
         self.depth = 0
+        self.annotation = None
 
     def set_attr(self, **attrs) -> None:
         """Attach/overwrite attributes while the span is open (e.g. a
@@ -77,11 +77,17 @@ class Span:
         self.depth = len(stack)
         self.tid = threading.get_ident()
         stack.append(self)
+        self.annotation = _profiler_annotation(self.name)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0_us = _now_us()
         return self
 
     def __exit__(self, *exc):
         dur = _now_us() - self.t0_us
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+            self.annotation = None
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -91,6 +97,16 @@ class Span:
             "args": self.attrs,
         })
         return False
+
+
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``, or None where jax
+    is not installed (``repro.obs`` itself never needs it)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation(name)
 
 
 class Tracer:
@@ -131,10 +147,11 @@ class Tracer:
 
 
 class TracedCallable:
-    """A callable wrapped in a ``dispatch/...`` span that blocks on the
-    result before the span closes — without that block, an async jit
-    dispatch returns immediately and the span would time only the Python
-    dispatch overhead. Disabled, the wrapper is one branch and a tail call.
+    """A callable wrapped in a ``dispatch/...`` span. The span times the
+    dispatch: it does not wait for the result, so the caller's pipeline of
+    asynchronous dispatches runs as it would untraced, and the device time
+    of the call is read from the device trace. Disabled, the wrapper is one
+    branch and a tail call.
 
     Attribute access forwards to the wrapped function, so jit surfaces
     (``.lower``, ``.trace``, ...) keep working on the wrapped object.
@@ -150,12 +167,8 @@ class TracedCallable:
     def __call__(self, *args, **kwargs) -> Any:
         if not _state.is_enabled():
             return self._fn(*args, **kwargs)
-        import jax  # deferred: repro.obs stays importable without jax
-
         with self._tracer.span(self._name, **self._attrs):
-            out = self._fn(*args, **kwargs)
-            jax.block_until_ready(out)
-        return out
+            return self._fn(*args, **kwargs)
 
     def __getattr__(self, item):
         return getattr(self._fn, item)

@@ -176,7 +176,6 @@ class SimServer:
                               .request.case, batch=nbatch, step=step,
                               fingerprint=tickets[0].fingerprint):
                     stacked = solver.batched_step(stacked)
-                    jax.block_until_ready(stacked)
             else:
                 stacked = solver.batched_step(stacked)
             t = t + solver.dt             # same accumulation as solo step()

@@ -51,7 +51,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import compat, obs
-from repro.core import perfmodel as pm, precision
+from repro.core import precision
 from repro.core.decomposition import PencilGrid
 from repro.core.fft3d import FFT3DPlan
 
@@ -239,35 +239,16 @@ class SpectralSolver(abc.ABC):
             "a solver steps the plan it was compiled for"
         return SolverState(fields=self.initial_fields(), t=0.0, n_steps=0)
 
-    def predict_step_us(self) -> float:
-        """The perf model's time for one ``step()`` of this solver's plan
-        (µs). Diagonal-kernel solvers price the full spectral roundtrip of
-        their plan (fused when the plan streams it); others price the same
-        roundtrip composed — the absolute number is a nominal-substrate
-        estimate either way, and the bench drift gate tracks its *error*
-        against a baseline rather than trusting it outright."""
-        cached = getattr(self, "_predict_step_us", None)
-        if cached is None:
-            g = self.plan.grid
-            diagonal = (type(self).spectral_kernel
-                        is not SpectralSolver.spectral_kernel)
-            est = pm.estimate_roundtrip_seconds(
-                self.n, g.pu, g.pv, spec=self.plan.spec(),
-                fused=self.plan.fused_roundtrip and diagonal,
-                mu=max(self.components, 1),
-                pu_axes=g.u_sizes, pv_axes=g.v_sizes)
-            cached = self._predict_step_us = round(est * 1e6, 3)
-        return cached
-
     def step(self, state: SolverState) -> SolverState:
+        """One Δt. Under ``repro.obs`` the ``dispatch/solver.step`` span
+        times the dispatch; the step's device time is in the device trace,
+        under the stage scopes."""
         if not obs.is_enabled():
             return SolverState(fields=self._stepj(state.fields),
                                t=state.t + self.dt, n_steps=state.n_steps + 1)
         with obs.span("dispatch/solver.step", case=self.case,
-                      engine=self.plan.comm_engine,
-                      model_predicted_us=self.predict_step_us()):
+                      engine=self.plan.comm_engine):
             fields = self._stepj(state.fields)
-            jax.block_until_ready(fields)
         return SolverState(fields=fields, t=state.t + self.dt,
                            n_steps=state.n_steps + 1)
 

@@ -18,8 +18,11 @@ component axis) so every solver reuses the same stepping machinery:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import tree_util
+
+from repro.obs import stages
 
 
 def _map(f, *trees):
@@ -60,14 +63,23 @@ def ifrk4(nonlin, decay, y, dt):
         k4 = N(E²·y + dt·E·k3)
         y ← E²·y + dt/6·(E²·k1 + 2E·(k2 + k3) + k4)
     """
-    e1 = jnp.exp(decay * (dt / 2))
-    e2 = e1 * e1
+    with jax.named_scope(stages.POINTWISE_IFRK4_FACTORS):
+        e1 = jnp.exp(decay * (dt / 2))
+        e2 = e1 * e1
+    combine = stages.POINTWISE_IFRK4_COMBINE
     mul = lambda e, t: _map(lambda a: e * a, t)
     k1 = nonlin(y)
-    k2 = nonlin(mul(e1, _axpy(dt / 2, k1, y)))
-    k3 = nonlin(_axpy(dt / 2, k2, mul(e1, y)))
-    k4 = nonlin(_axpy(dt, mul(e1, k3), mul(e2, y)))
-    return _map(
-        lambda yi, a, b, c, d: e2 * yi + (dt / 6) * (e2 * a + 2 * e1 * (b + c)
-                                                     + d),
-        y, k1, k2, k3, k4)
+    with jax.named_scope(combine):
+        y2 = mul(e1, _axpy(dt / 2, k1, y))
+    k2 = nonlin(y2)
+    with jax.named_scope(combine):
+        y3 = _axpy(dt / 2, k2, mul(e1, y))
+    k3 = nonlin(y3)
+    with jax.named_scope(combine):
+        y4 = _axpy(dt, mul(e1, k3), mul(e2, y))
+    k4 = nonlin(y4)
+    with jax.named_scope(combine):
+        return _map(
+            lambda yi, a, b, c, d: e2 * yi + (dt / 6) * (
+                e2 * a + 2 * e1 * (b + c) + d),
+            y, k1, k2, k3, k4)

@@ -19,11 +19,13 @@ The Taylor–Green vortex validation — monotone viscous energy decay and
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import spectral as sp
 from repro.core.fft3d import fft3d_vector_local
+from repro.obs import stages
 from repro.solvers import integrators
 from repro.solvers.base import SpectralSolver
 
@@ -48,8 +50,6 @@ class NavierStokesSolver(SpectralSolver):
         at N=256). The same Leray projection every step ends with puts
         the t=0 state on the manifold the solver keeps it on.
         """
-        import jax
-
         from repro import compat
 
         nx = self.n[0]
@@ -71,7 +71,8 @@ class NavierStokesSolver(SpectralSolver):
         return fwd(u0)
 
     def step_fields(self, plan, fields):
-        decay = -self.nu * sp.k_squared(plan, fields[0].dtype)
+        with jax.named_scope(stages.POINTWISE_IFRK4_FACTORS):
+            decay = -self.nu * sp.k_squared(plan, fields[0].dtype)
 
         def nonlin(y):
             return sp.rotational_nonlinear_term(

@@ -28,8 +28,9 @@ import sys
 
 
 #: current bench-document schema: v2 rows may carry ``p50_us``/``p95_us``
-#: (tail timing) and ``model_predicted_us``/``model_err`` (perf-model drift)
-#: next to ``us_per_call``; readers accept both generations
+#: (tail timing) and the perf model's prediction with its error
+#: ``model_err`` (perf-model drift) next to ``us_per_call``; readers accept
+#: both generations
 BENCH_SCHEMA = "bench-fft/v2"
 BENCH_SCHEMAS = ("bench-fft/v1", BENCH_SCHEMA)
 

@@ -69,11 +69,11 @@ def test_disabled_traced_call_is_transparent():
 def test_span_nesting_records_parent_and_depth():
     obs.enable()
     with obs.span("dispatch/outer", engine="torus"):
-        with obs.span("trace/inner", round=3) as sp:
+        with obs.span("tune/inner", round=3) as sp:
             sp.set_attr(bytes=64)
     events = {e["name"]: e for e in obs.tracer.events()}
-    assert set(events) == {"dispatch/outer", "trace/inner"}
-    outer, inner = events["dispatch/outer"], events["trace/inner"]
+    assert set(events) == {"dispatch/outer", "tune/inner"}
+    outer, inner = events["dispatch/outer"], events["tune/inner"]
     assert outer["parent"] == "" and outer["depth"] == 0
     assert inner["parent"] == "dispatch/outer" and inner["depth"] == 1
     assert inner["args"] == {"round": 3, "bytes": 64}
@@ -139,13 +139,107 @@ def test_metrics_counters_accumulate_and_gauges_overwrite():
 
 
 # ---------------------------------------------------------------------------
+# the profiler's clock: spans on the host plane, dispatch spans never block
+# ---------------------------------------------------------------------------
+
+def test_enabled_span_lands_on_profiler_host_plane(tmp_path):
+    import glob
+    import sys
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import tracing
+
+    f = jax.jit(lambda x: x * 2)
+    f(jnp.ones(8)).block_until_ready()
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span(tracing.WINDOW):
+            with obs.span("bench.dispatch", step=0):
+                f(jnp.ones(8)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    cap = tracing.load(path)
+    (name, start, end), = cap.host
+    assert name == "bench.dispatch"
+    assert cap.window[0] <= start <= end <= cap.window[1]
+    # the tracer's own events are still recorded
+    assert [e["name"] for e in obs.tracer.events()] == [
+        "bench.dispatch", tracing.WINDOW]
+
+
+def test_disabled_span_imports_no_profiler(monkeypatch):
+    import builtins
+
+    real_import = builtins.__import__
+
+    def guarded(name, *args, **kwargs):
+        assert name != "jax.profiler", "disabled span touched the profiler"
+        return real_import(name, *args, **kwargs)
+
+    monkeypatch.setattr(builtins, "__import__", guarded)
+    with obs.span("dispatch/x"):
+        pass
+
+
+def _record_blocks(monkeypatch):
+    import jax
+
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(x) or real(x))
+    return calls
+
+
+def test_traced_call_does_not_block(monkeypatch):
+    import jax.numpy as jnp
+
+    calls = _record_blocks(monkeypatch)
+    obs.enable()
+    wrapped = obs.traced_call(lambda x: x + 1, "dispatch/fft3d.fwd")
+    out = wrapped(jnp.ones(4))
+    obs.disable()
+    assert calls == []
+    assert float(out[0]) == 2.0
+    assert [e["name"] for e in obs.tracer.events()] == ["dispatch/fft3d.fwd"]
+
+
+def test_solver_step_does_not_block(monkeypatch):
+    import numpy as np
+
+    from repro import compat
+    from repro.solvers import make_solver
+
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    solver = make_solver("heat", mesh, 8, dtype="float32")
+    state = solver.init_state()
+    calls = _record_blocks(monkeypatch)
+    obs.enable()
+    nxt = solver.step(state)
+    obs.disable()
+    assert calls == []
+    assert nxt.n_steps == 1 and np.all(np.isfinite(np.asarray(nxt.fields)))
+    (ev,) = obs.tracer.events()
+    assert ev["name"] == "dispatch/solver.step"
+    assert ev["args"] == {"case": "heat", "engine": solver.plan.comm_engine}
+
+
+# ---------------------------------------------------------------------------
 # Chrome-trace export (the document Perfetto / chrome://tracing load)
 # ---------------------------------------------------------------------------
 
 def test_chrome_trace_document_schema(tmp_path):
     obs.enable()
     with obs.span("dispatch/fft3d.fwd", engine="torus"):
-        with obs.span("trace/fft3d.fold_xy", grid_dim="u"):
+        with obs.span("tune/candidate", grid_dim="u"):
             pass
     obs.metrics.inc("comm.wire_bytes", 128)
     obs.disable()
@@ -160,9 +254,9 @@ def test_chrome_trace_document_schema(tmp_path):
     assert doc["meta"] == {"devices": 8}
     assert doc["metrics"]["counters"]["comm.wire_bytes"] == 128
     events = {e["name"]: e for e in doc["traceEvents"]}
-    assert set(events) == {"dispatch/fft3d.fwd", "trace/fft3d.fold_xy"}
-    ev = events["trace/fft3d.fold_xy"]
-    assert ev["ph"] == "X" and ev["cat"] == "trace"
+    assert set(events) == {"dispatch/fft3d.fwd", "tune/candidate"}
+    ev = events["tune/candidate"]
+    assert ev["ph"] == "X" and ev["cat"] == "tune"
     assert ev["args"]["grid_dim"] == "u"
     assert ev["args"]["parent"] == "dispatch/fft3d.fwd"
     assert events["dispatch/fft3d.fwd"]["cat"] == "dispatch"
