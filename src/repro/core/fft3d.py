@@ -44,9 +44,14 @@ ring over the product group; ``spec.fabric`` is the derived §5.5 fabric
 ("switched" | "torus") the chosen engine runs on.
 
 Real-to-complex: the X phase uses the general complex engine on real input
-and keeps N/2+1 bins (padded to a Pu-divisible length), exactly the paper's
-choice (§3.2.5, §3.4: "we prefer a more general and flexible architecture").
-``r2c_packed=True`` switches on the beyond-paper even/odd packed real FFT.
+and keeps N/2+1 bins (padded to a Pu-divisible length), the paper's choice
+(§3.2.5, §3.4: "we prefer a more general and flexible architecture"). The
+engine takes two real lines per complex FFT, the first and second halves
+of the pencil's Ny/Pu axis as its real and imaginary planes, and their
+spectra are untangled from the one result; the inverse rebuilds both
+Hermitian spectra into one complex line per pair (``kops.rfft1d`` /
+``kops.irfft1d``). ``r2c_packed=True`` switches on the beyond-paper
+even/odd packed real FFT.
 
 All ``*_local`` functions run inside ``shard_map``; ``make_fft3d`` builds the
 jitted global-array entry points.

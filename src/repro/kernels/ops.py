@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ref as _ref
 from repro.kernels.fft_radix2 import fft1d_pallas, ifft1d_pallas
 from repro.obs import stages
@@ -65,9 +66,19 @@ def fft1d(x_re, x_im, *, axis: int = -1, backend: str = "pallas", inverse: bool 
 def rfft1d(x, *, axis: int = -1, backend: str = "pallas", packed: bool = False):
     """Real-to-complex FFT keeping N/2+1 bins (paper §3.2.5).
 
+    The faithful default mirrors the thesis: the general complex engine
+    does the real transform. It runs two real lines per complex FFT: where
+    a leading axis has even length (the outermost such axis), the first
+    half of its lines is the real plane and the second half the imaginary
+    plane of one ``fft1d`` over half the lines, and
+    ``A[k] = (Z[k] + conj Z[-k]) / 2``, ``B[k] = (Z[k] - conj Z[-k]) / 2i``
+    untangle the two spectra (scope ``relayout.r2c_untangle``). With no
+    such axis each line runs alone as ``(x, 0)``. The choice follows the
+    shape only; the trace counts it as ``fft.r2c.paired`` or
+    ``fft.r2c.unpaired``.
+
     ``packed=True`` enables the beyond-paper even/odd packing (one N/2-point
-    complex FFT instead of an N-point one). The faithful default mirrors the
-    thesis: run the general complex engine on (x, 0). Packing requires an
+    complex FFT instead of an N-point one). Packing requires an
     even length — the even/odd split assumes ``n % 2 == 0``; odd lengths
     raise at trace time rather than silently mangling the spectrum.
     """
@@ -80,11 +91,32 @@ def rfft1d(x, *, axis: int = -1, backend: str = "pallas", packed: bool = False):
             f"use packed=False for odd lengths")
     if packed:
         yr, yi = _ref.rfft_packed_planar(xr) if backend != "pallas" else _rfft_packed_pallas(xr)
-    else:
+        return _unmove_last(yr, axis), _unmove_last(yi, axis)
+    p = _pair_axis(xr.shape)
+    obs.metrics.inc("fft.r2c.unpaired" if p is None else "fft.r2c.paired")
+    if p is None:
         zr, zi = fft1d(xr, jnp.zeros_like(xr), axis=-1, backend=backend)
         with jax.named_scope(stages.RELAYOUT_R2C_CROP):
             yr, yi = zr[..., : n // 2 + 1], zi[..., : n // 2 + 1]
+    else:
+        zr, zi = fft1d(*jnp.split(xr, 2, axis=p), axis=-1, backend=backend)
+        h = n // 2
+
+        def mirrored(z):  # z[(n - k) mod n] for k = 0..n/2
+            return jnp.concatenate([z[..., :1], jnp.flip(z[..., n - h:], -1)], -1)
+
+        with jax.named_scope(stages.RELAYOUT_R2C_UNTANGLE):
+            rr, ri = mirrored(zr), mirrored(zi)
+            zr, zi = zr[..., : h + 1], zi[..., : h + 1]
+            yr = jnp.concatenate([0.5 * (zr + rr), 0.5 * (zi + ri)], axis=p)
+            yi = jnp.concatenate([0.5 * (zi - ri), 0.5 * (rr - zr)], axis=p)
     return _unmove_last(yr, axis), _unmove_last(yi, axis)
+
+
+def _pair_axis(shape) -> int | None:
+    """The outermost leading axis of even length, whose halves are paired
+    line by line, or None."""
+    return next((a for a, d in enumerate(shape[:-1]) if d % 2 == 0), None)
 
 
 def _rfft_packed_pallas(x):
@@ -110,14 +142,40 @@ def _rfft_packed_pallas(x):
 
 @functools.partial(jax.jit, static_argnames=("axis", "backend", "n"))
 def irfft1d(x_re, x_im, *, n: int, axis: int = -1, backend: str = "pallas"):
-    """Complex-to-real inverse, reconstructing the Hermitian upper half."""
+    """Complex-to-real inverse of N/2+1 bins, rebuilding the Hermitian
+    upper half (scope ``relayout.c2r_hermitian``).
+
+    Pairs spectra as :func:`rfft1d` pairs lines: spectrum A (first half of
+    the pairing axis) and spectrum B (second half) build one full line
+    ``Z = A_full + i B_full``, and one inverse ``fft1d`` over half the lines
+    returns line a as its real plane and line b as its imaginary plane. The
+    imaginary parts of bin 0 and of bin n/2 (even n) are dropped first, as
+    ``numpy.fft.irfft`` drops them; they would leak into the partner line.
+    Unpaired, each line's own Hermitian extension runs and the imaginary
+    plane of the result is discarded.
+    """
     xr, xi = _move_last(x_re, axis), _move_last(x_im, axis)
-    k = xr.shape[-1]
-    assert k == n // 2 + 1, (k, n)
-    # rebuild bins n/2+1 .. n-1 by conjugate symmetry
+    h = n // 2
+    assert xr.shape[-1] == h + 1, (xr.shape[-1], n)
+    p = _pair_axis(xr.shape)
+    obs.metrics.inc("fft.r2c.unpaired" if p is None else "fft.r2c.paired")
+
+    def upper(v):  # bins n-1 .. h+1 are the conjugates of bins 1 .. n-h-1
+        return jnp.flip(v[..., 1: n - h], -1)
+
     with jax.named_scope(stages.RELAYOUT_C2R_HERMITIAN):
-        idx = jnp.arange(n // 2 - 1, 0, -1)
-        fr = jnp.concatenate([xr, xr[..., idx]], axis=-1)
-        fi = jnp.concatenate([xi, -xi[..., idx]], axis=-1)
-    yr, _ = fft1d(fr, fi, axis=-1, backend=backend, inverse=True)
+        if p is None:
+            fr = jnp.concatenate([xr, upper(xr)], -1)
+            fi = jnp.concatenate([xi, -upper(xi)], -1)
+        else:
+            ar, br = jnp.split(xr, 2, axis=p)
+            k = jax.lax.broadcasted_iota(jnp.int32, ar.shape, ar.ndim - 1)
+            real_bin = (k == 0) | (k == h) if n % 2 == 0 else k == 0
+            ai, bi = (jnp.where(real_bin, 0, v) for v in jnp.split(xi, 2, axis=p))
+            # Z[k] = A[k] + i B[k] up to n/2, conj A[n-k] + i conj B[n-k] above
+            fr = jnp.concatenate([ar - bi, upper(ar + bi)], -1)
+            fi = jnp.concatenate([ai + br, upper(br - ai)], -1)
+    yr, yi = fft1d(fr, fi, axis=-1, backend=backend, inverse=True)
+    if p is not None:
+        yr = jnp.concatenate([yr, yi], axis=p)
     return _unmove_last(yr, axis)

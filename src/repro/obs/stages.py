@@ -28,6 +28,7 @@ RELAYOUT_FOLD_UNPACK = "relayout.fold_unpack"    # merge_blocks
 RELAYOUT_PERMUTE = "relayout.permute"            # pencil transposes
 RELAYOUT_R2C_PAD = "relayout.r2c_pad"            # kx padding after rfft
 RELAYOUT_R2C_CROP = "relayout.r2c_crop"          # N/2+1 slices
+RELAYOUT_R2C_UNTANGLE = "relayout.r2c_untangle"  # rfft's paired lines' spectra
 RELAYOUT_C2R_HERMITIAN = "relayout.c2r_hermitian"  # irfft's upper half
 #: not a scope: the trace reduction's name for XLA's layout copy of a
 #: program argument (an op whose producers lead back to a parameter)
@@ -45,7 +46,8 @@ POINTWISE_DIAGONAL = "pointwise.diagonal"
 #: every scope the program puts on its work
 SCOPES = (FFT_X, FFT_Y, FFT_Z, FOLD_EXCHANGE,
           RELAYOUT_FOLD_PACK, RELAYOUT_FOLD_UNPACK, RELAYOUT_PERMUTE,
-          RELAYOUT_R2C_PAD, RELAYOUT_R2C_CROP, RELAYOUT_C2R_HERMITIAN,
+          RELAYOUT_R2C_PAD, RELAYOUT_R2C_CROP, RELAYOUT_R2C_UNTANGLE,
+          RELAYOUT_C2R_HERMITIAN,
           POINTWISE_CURL, POINTWISE_CROSS, POINTWISE_DEALIAS,
           POINTWISE_PROJECT, POINTWISE_IFRK4_FACTORS,
           POINTWISE_IFRK4_COMBINE, POINTWISE_DIAGONAL)

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.kernels import ref
 from repro.kernels.fft_radix2 import fft1d_pallas, ifft1d_pallas, pick_batch_tile
 from repro.kernels.ops import fft1d, irfft1d, rfft1d
@@ -99,6 +100,42 @@ def test_rfft_packed_rejects_odd_length(backend):
     yr, yi = rfft1d(x, backend="jnp", packed=False)
     z = np.fft.rfft(np.asarray(x, np.float64))
     assert rel_l2(yr, z.real) < 1e-10 and rel_l2(yi, z.imag) < 1e-10
+
+
+#: (backend, n): every backend at a power of two, and the jnp engine at an
+#: even and an odd length no radix-2 engine takes
+R2C_ENGINES = [("jnp", 16), ("jnp", 12), ("jnp", 9), ("ref", 16),
+               ("pallas", 16), ("mxu", 16)]
+#: leading shapes and the path each takes: pairs along the outermost even
+#: axis, or lines one by one where no leading axis is even
+R2C_LEADS = [((4,), "paired"), ((3, 4), "paired"), ((3,), "unpaired"),
+             ((5, 3), "unpaired"), ((), "unpaired")]
+
+
+@pytest.mark.parametrize("lead,path", R2C_LEADS,
+                         ids=["4", "3x4", "3", "5x3", "none"])
+@pytest.mark.parametrize("backend,n", R2C_ENGINES,
+                         ids=[f"{b}-{n}" for b, n in R2C_ENGINES])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+def test_rfft_irfft_pair_lines_like_numpy(backend, n, lead, path, dtype):
+    rng = np.random.default_rng(n + len(lead))
+    x = rng.standard_normal(lead + (n,))
+    # a spectrum with non-zero imaginary parts in every bin, DC and
+    # Nyquist too: irfft drops those two as numpy does
+    z = rng.standard_normal(lead + (n // 2 + 1,)) \
+        + 1j * rng.standard_normal(lead + (n // 2 + 1,))
+    rfft1d.clear_cache()
+    irfft1d.clear_cache()
+    with obs.capture() as (_, metrics):
+        yr, yi = rfft1d(jnp.asarray(x, dtype), backend=backend)
+        back = irfft1d(jnp.asarray(z.real, dtype), jnp.asarray(z.imag, dtype),
+                       n=n, backend=backend)
+    assert metrics.counters() == {f"fft.r2c.{path}": 2}
+    want = np.fft.rfft(x)
+    assert yr.dtype == dtype and back.dtype == dtype
+    assert rel_l2(yr, want.real) < TOL[dtype]
+    assert rel_l2(yi, want.imag) < TOL[dtype]
+    assert rel_l2(back, np.fft.irfft(z, n)) < TOL[dtype]
 
 
 def test_pick_batch_tile_respects_vmem():

@@ -161,8 +161,8 @@ def stripped(hlo_text: str) -> str:
     return META.sub("", "\n\n".join(blocks))
 
 
-def cell_programs(topo, shape) -> dict:
-    """The compiled HLO of the speed3d r2c pair and of a TGV step."""
+def cell_lowerings(topo, shape) -> dict:
+    """Thunks that lower the speed3d r2c pair and a TGV step."""
     mesh = mesh_of(topo, shape)
     fwd, inv, plan = make_fft3d(mesh, N_STAGES, real=True)
     sharding = NamedSharding(mesh, plan.grid.pencil_spec())
@@ -174,9 +174,14 @@ def cell_programs(topo, shape) -> dict:
     fields = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=ssh),
         jax.eval_shape(solver.initial_fields))
-    return {"fwd": fwd.lower(x).compile().as_text(),
-            "inv": inv.lower(*spectrum).compile().as_text(),
-            "tgv": solver._stepj.lower(fields).compile().as_text()}
+    return {"fwd": lambda: fwd.lower(x), "inv": lambda: inv.lower(*spectrum),
+            "tgv": lambda: solver._stepj.lower(fields)}
+
+
+def cell_programs(topo, shape) -> dict:
+    """The compiled HLO of the speed3d r2c pair and of a TGV step."""
+    return {name: lower().compile().as_text()
+            for name, lower in cell_lowerings(topo, shape).items()}
 
 
 @pytest.fixture(scope="module")
@@ -216,11 +221,29 @@ def test_local_instructions_resolve_to_stages(scoped, shape):
         assert {"fft.x", "fft.y", "fft.z"} <= seen, (name, seen)
         if shape == (2, 2):
             assert "fold.exchange" in seen, name
+    # the paired x pass: its untangle and Hermitian fusions are staged, and
+    # (above) none of their ops or of XLA's copies after them is unstaged
     tgv = _stages(scoped[shape]["tgv"])
     assert {"pointwise.curl", "pointwise.cross", "pointwise.dealias",
             "pointwise.project", "pointwise.ifrk4_factors",
             "pointwise.ifrk4_combine", "relayout.permute",
-            "relayout.c2r_hermitian"} <= tgv, tgv
+            "relayout.r2c_untangle", "relayout.c2r_hermitian"} <= tgv, tgv
+    assert "relayout.r2c_untangle" in _stages(scoped[shape]["fwd"])
+    assert "relayout.c2r_hermitian" in _stages(scoped[shape]["inv"])
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_cell_programs_pair_real_lines(topo, shape):
+    # each program traced afresh counts the path its x pass takes
+    from repro import obs
+
+    for name, lower in cell_lowerings(topo, shape).items():
+        jax.clear_caches()
+        with obs.capture() as (_, metrics):
+            lower()
+        counts = metrics.counters()
+        assert counts.get("fft.r2c.paired", 0) >= 1, (name, counts)
+        assert "fft.r2c.unpaired" not in counts, (name, counts)
 
 
 def walk(instr, graph) -> tuple:
